@@ -114,6 +114,7 @@ from repro.core.batch import batch_greedy_diverse, batch_optimal_diverse
 from repro.core.batch_progressive import (_batched_adjacency, _mask_prefix,
                                           batch_pss)
 from repro.kernels import ops as kops
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve.scheduler import LaneScheduler, jain_fairness, percentile
 
 
@@ -1469,6 +1470,7 @@ def main(argv=None):
                          "trend JSON (skewed/open modes)")
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.mode == "engine":
         kwargs = {}
         if args.n:

@@ -89,7 +89,7 @@ def load_graph(name: str, n: int = N_DEFAULT, M: int = 12,
         z = np.load(path)
         g = make_flat_graph(x, z["neighbors"],
                             z["upper"] if z["upper"].size else None,
-                            int(z["entry"]), metric)
+                            z["entry"], metric)
         return g, x, metric
     if builder == "hnsw":
         from repro.index.hnsw import build_hnsw
@@ -99,5 +99,5 @@ def load_graph(name: str, n: int = N_DEFAULT, M: int = 12,
         from repro.index.flat import build_knn_graph
         g = build_knn_graph(x, metric=metric, M=M)
     np.savez(path, neighbors=np.asarray(g.neighbors),
-             upper=np.asarray(g.upper), entry=int(g.entry))
+             upper=np.asarray(g.upper), entry=np.asarray(g.entry))
     return g, x, metric

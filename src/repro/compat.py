@@ -1,42 +1,15 @@
-"""Version compatibility shims for the jax API surface this repo uses.
+"""Mesh construction with the axis types this repo relies on.
 
-The repo targets the modern jax API (``jax.make_mesh(..., axis_types=...)``,
-``jax.shard_map(..., check_vma=...)``) but must also run on the 0.4.x line
-shipped in CI/container images, where mesh axis types do not exist yet and
-shard_map lives in ``jax.experimental`` under the ``check_rep`` spelling.
-Everything else (``jax.tree``, ``jax.sharding.NamedSharding``) is stable
-across the supported range.
+Every mesh here uses ``AxisType.Auto`` axes: shardings are propagated by
+the compiler and ``shard_map`` bodies take explicit ``in_specs``.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-_HAS_AXIS_TYPES = "axis_types" in inspect.signature(jax.make_mesh).parameters
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _HAS_AXIS_TYPES:
-        from jax.sharding import AxisType
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (newer jax) or the psum(1) classic."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` when available, else the experimental spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

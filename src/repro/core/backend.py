@@ -137,15 +137,18 @@ class LaneBackend(Protocol):
         ...
 
 
-@runtime_checkable
 class RescalableBackend(LaneBackend, Protocol):
     """A ``LaneBackend`` whose capacity can follow traffic (contract 16).
 
     Implemented by ``ShardedEngine`` (and delegated through
     ``index.mutable.MutableBackend``); the single-host ``ProgressiveEngine``
-    is not rescalable, so the scheduler's elastic trigger feature-detects
-    this protocol and stays inert otherwise. The contract mirrors the
-    epoch swap's two-phase shape, but the barrier is quiesce-FREE:
+    is not rescalable. Conformance is declared, not probed: an
+    implementation sets ``rescalable = True`` and the scheduler's elastic
+    trigger asks ``is_rescalable`` (a wrapper that serves the surface
+    through ``__getattr__`` is invisible to a runtime Protocol check on
+    Python >= 3.12, which inspects attributes statically). The contract
+    mirrors the epoch swap's two-phase shape, but the barrier is
+    quiesce-FREE:
 
     * ``prepare_rescale`` pays the expensive halves (repartitioning the
       corpus, compiling the target mesh's dispatch ladder) ahead of load;
@@ -155,6 +158,8 @@ class RescalableBackend(LaneBackend, Protocol):
       certified result still passes ``theorem2_recheck``. Resharding is a
       capacity knob, never a results knob.
     """
+
+    rescalable: bool
 
     @property
     def num_shards(self) -> int:
@@ -175,3 +180,8 @@ class RescalableBackend(LaneBackend, Protocol):
         """Migrate corpus + in-flight lanes to the prepared ``shards``
         mesh; False if already there."""
         ...
+
+
+def is_rescalable(backend) -> bool:
+    """True when ``backend`` declares the ``RescalableBackend`` surface."""
+    return bool(getattr(backend, "rescalable", False))

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from repro import quant
 from repro.core import queue as qmod
-from repro.core.graph import FlatGraph, descend
+from repro.core.graph import FlatGraph, entry_for
 from repro.core.queue import Queue
 from repro.kernels import ops as kops
 
@@ -41,8 +41,10 @@ class SearchState(NamedTuple):
 @functools.partial(jax.jit, static_argnames=("capacity", "use_descent"))
 def init_state(graph: FlatGraph, q: jnp.ndarray, capacity: int,
                use_descent: bool = True) -> SearchState:
-    """Start state: queue seeded with the entry point (after HNSW descent)."""
-    entry = descend(graph, q) if use_descent and graph.num_upper_levels else graph.entry
+    """Start state: queue seeded with the entry point (after HNSW descent,
+    or the best of the graph's entry candidates)."""
+    entry = (entry_for(graph, q) if use_descent or not graph.num_upper_levels
+             else graph.entry)
     if quant.is_quantized(graph.vectors):
         qprep = quant.prepare_query(graph.vectors, q, graph.metric)
         s0 = quant.score_rows(qprep, graph.vectors,

@@ -5,7 +5,10 @@ equivalent depth-first branch-and-bound as a ``lax.while_loop`` over a
 fixed-capacity stack (depth <= k+1 thanks to in-place sibling cursors).
 Candidates are processed in (score desc, id asc) order; the admissible bound
 is current score + sum of the next best remaining scores (conflicts
-ignored) — identical to the python oracle ``div_astar_ref``.
+ignored) — identical to the python oracle ``div_astar_ref``. That bound
+only falls along a level (scores are sorted), so the first sibling that
+fails it ends the level: a long tail of hopeless candidates costs one
+step, not one step each.
 
 Pruning keeps a state alive if it could improve the incumbent of ANY size
 m' <= k, so the optimal sets of every size 1..k come out certified (PSS
@@ -105,6 +108,11 @@ def div_astar(scores: jnp.ndarray, adj: jnp.ndarray, k: int,
                 promising = jnp.any(jnp.where(
                     feasible, bounds > c.best_scores, False))
                 do_push = (m < k) & promising
+                # scores are sorted, so the plain bound only falls along
+                # this level: once it fails, no later sibling can improve
+                # any size either, and the level is done
+                c = c._replace(cursor=jnp.where(
+                    promising, c.cursor, c.cursor.at[depth].set(K)))
 
                 def push(c: Carry):
                     nt = c.t + 1

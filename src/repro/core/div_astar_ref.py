@@ -8,7 +8,8 @@ score order with an admissible bound (current score + sum of the best
 remaining scores, conflicts ignored). A state is pruned only when its bound
 cannot improve the incumbent of ANY size m in (|S|, k] — pruning on size-k
 alone could discard states that improve some smaller-size optimum, which
-Theorem 2 consumes.
+Theorem 2 consumes. Scores are sorted, so that bound only falls along a
+level: the first sibling that fails it ends the level.
 
 This file is the test oracle for ``repro.core.div_astar`` (the JAX version)
 and the ground-truth generator for recall in the benchmarks.
@@ -71,6 +72,7 @@ def div_astar_ref(scores: np.ndarray, adj: np.ndarray, k: int,
             best_scores[m - 1] = new_score
             best_sets[m - 1] = list(new_chosen)
         if m >= k:
+            stack[-1] = (chosen, banned, score, n)   # sorted: none better
             continue
         # prune unless some size m' in (m, k] could improve
         new_banned = banned | adj_sorted[cursor]
@@ -82,6 +84,10 @@ def div_astar_ref(scores: np.ndarray, adj: np.ndarray, k: int,
                 break
         if promising:
             stack.append((new_chosen, new_banned, new_score, cursor + 1))
+        else:
+            # the bound only falls along a level: no later sibling can
+            # improve any size either
+            stack[-1] = (chosen, banned, score, n)
 
     # map sorted-local indices back to input-local indices
     out_sets = []

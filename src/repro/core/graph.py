@@ -6,7 +6,10 @@ The numpy HNSW builder (``repro.index.hnsw``) emits:
   neighbors  int32[N, M0]       level-0 adjacency, -1 padded
   upper      int32[Lu, N, Mu]   upper-level adjacency (rows of non-member
                                 nodes are all -1); may have Lu == 0
-  entry      int32              entry node at the top level
+  entry      int32 | int32[P]   entry node at the top level, or P entry
+                                candidates (level-0 graphs): each search
+                                starts at the candidate most similar to
+                                its query (``entry_for``)
 
 ``metric`` travels as static aux data so jitted searchers specialize on it.
 """
@@ -21,6 +24,7 @@ import numpy as np
 
 from repro import quant
 from repro.core.similarity import query_sim
+from repro.kernels import ops as kops
 
 
 @jax.tree_util.register_dataclass
@@ -50,11 +54,13 @@ class FlatGraph:
 
 
 def make_flat_graph(vectors: Any, neighbors: Any, upper: Any | None,
-                    entry: int, metric: str) -> FlatGraph:
+                    entry, metric: str) -> FlatGraph:
     """``vectors`` may be a float array OR a quantized corpus
     (``quant.Int8Corpus`` / ``quant.PQCorpus``): the beam search scores
     whichever representation the graph carries. Quantized graphs are
-    level-0 only (the greedy upper-level descent reads float rows)."""
+    level-0 only (the greedy upper-level descent reads float rows).
+    ``entry`` is one node id, or a vector of entry candidates on a graph
+    without upper levels."""
     if quant.is_quantized(vectors):
         if upper is not None and getattr(upper, "shape", (0,))[0] != 0:
             raise ValueError(
@@ -67,8 +73,48 @@ def make_flat_graph(vectors: Any, neighbors: Any, upper: Any | None,
         upper = jnp.zeros((0, vectors.shape[0], 1), dtype=jnp.int32)
     else:
         upper = jnp.asarray(upper, dtype=jnp.int32)
+        if np.ndim(entry):
+            raise ValueError("entry candidates need a level-0 graph; the "
+                             "upper-level descent starts at one node")
     return FlatGraph(vectors, neighbors, upper,
                      jnp.asarray(entry, dtype=jnp.int32), metric)
+
+
+def embed_live_graph(g: FlatGraph, live: np.ndarray, vectors
+                     ) -> FlatGraph:
+    """Re-index a graph built over the rows ``live`` of ``vectors`` to
+    positional ids: every other row keeps its vector and has no edges, in
+    or out, at any level."""
+    live = np.asarray(live)
+
+    def glob(local):
+        local = np.asarray(local)
+        return np.where(local >= 0, live[np.maximum(local, 0)], -1)
+
+    n = vectors.shape[0]
+    nbrs = np.full((n, g.degree), -1, np.int32)
+    nbrs[live] = glob(g.neighbors)
+    upper = np.full((g.num_upper_levels, n, g.upper.shape[2]), -1, np.int32)
+    upper[:, live] = glob(g.upper)
+    return make_flat_graph(vectors, nbrs, upper, live[np.asarray(g.entry)],
+                           g.metric)
+
+
+def entry_for(graph: FlatGraph, q: jnp.ndarray) -> jnp.ndarray:
+    """The level-0 start node for query ``q``: the upper-level descent's
+    result, the graph's one entry node, or the entry candidate most
+    similar to ``q`` (first index on ties)."""
+    if graph.num_upper_levels:
+        return descend(graph, q)
+    if graph.entry.ndim == 0:
+        return graph.entry
+    cands = graph.entry
+    if quant.is_quantized(graph.vectors):
+        qprep = quant.prepare_query(graph.vectors, q, graph.metric)
+        sims = quant.score_rows(qprep, graph.vectors, cands, graph.metric)
+    else:
+        sims = kops.batch_similarity(q, graph.vectors[cands], graph.metric)
+    return cands[jnp.argmax(sims)]
 
 
 def descend(graph: FlatGraph, q: jnp.ndarray) -> jnp.ndarray:
@@ -113,6 +159,6 @@ def to_host(graph: FlatGraph) -> dict:
         vectors=np.asarray(graph.vectors),
         neighbors=np.asarray(graph.neighbors),
         upper=np.asarray(graph.upper),
-        entry=int(graph.entry),
+        entry=np.asarray(graph.entry),
         metric=graph.metric,
     )

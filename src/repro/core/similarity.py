@@ -57,7 +57,9 @@ def pairwise_sim(x: jnp.ndarray, y: jnp.ndarray, metric: Metric) -> jnp.ndarray:
     """Pairwise similarity matrix between rows of ``x``[m, d] and ``y``[n, d]."""
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
-    dots = x @ y.T
+    # full f32 passes: the TPU's default f32 matmul rounds operands to bf16,
+    # which moves near-eps edges and Theorem-2 values off the exact answer
+    dots = jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
     if metric == "ip":
         return dots
     if metric == "cos":

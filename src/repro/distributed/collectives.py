@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 
 
 def allgather_matmul(x_shard, w_local, axis: str):
@@ -30,7 +29,7 @@ def ring_allgather_matmul(x_shard, w_local, axis: str):
     x_shard [Bs, K] (leading dim sharded over ``axis``), w_local [K, N].
     Returns y [Bs*P, K->N] identical to the baseline (up to fp reorder).
     """
-    p = axis_size(axis)
+    p = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     bs = x_shard.shape[0]
     # receive from the next rank each step: after t hops we hold shard me+t

@@ -58,7 +58,8 @@ import numpy as np
 
 from repro import quant
 from repro.core import theorems
-from repro.core.graph import FlatGraph, make_flat_graph
+from repro.core.backend import is_rescalable
+from repro.core.graph import FlatGraph, embed_live_graph, make_flat_graph
 from repro.core.pgs import DiverseResult
 from repro.kernels import ops as kops
 
@@ -169,6 +170,13 @@ class MutableIndex:
         self.delta_start = self._n
         self._pending: tuple[int, object] | None = None
         self._thread: threading.Thread | None = None
+        #: exception a background rebuild died with; re-raised (once) by
+        #: ``wait_rebuild`` / ``swap_ready`` so a failed build is never lost
+        self._error: Exception | None = None
+        #: tombstone mask of the rebuild in flight (running or pending), and
+        #: whether a later delete hit a row that snapshot holds live
+        self._snap_dead: np.ndarray | None = None
+        self._stale = False
         self._lock = threading.Lock()
         self._delta_codes: tuple[int, object] | None = None
         if self.shards is not None:
@@ -268,6 +276,11 @@ class MutableIndex:
         if (ids < 0).any() or (ids >= self._n).any():
             raise KeyError(f"delete of unknown id(s) outside [0, {self._n})")
         newly = int((~self._del[ids]).sum())
+        snap_dead = self._snap_dead
+        if snap_dead is not None:
+            held = ids[ids < snap_dead.shape[0]]
+            if not snap_dead[held].all():
+                self._stale = True
         self._del[ids] = True
         self.num_deleted += newly
         self.version += 1
@@ -442,47 +455,66 @@ class MutableIndex:
                                      scale_rows=self.scale_rows,
                                      seed=self.seed)
         return make_flat_graph(corp, np.asarray(g.neighbors), None,
-                               int(g.entry), self.metric)
+                               np.asarray(g.entry), self.metric)
 
-    def _build(self, snap: np.ndarray):
+    def _build(self, snap: np.ndarray, dead: np.ndarray | None = None):
         """Build the epoch structure over a row snapshot (thread-safe: pure
-        function of ``snap``; tombstoned rows stay in place so ids remain
-        positional)."""
+        function of ``snap`` and its tombstone mask ``dead``). Tombstoned
+        rows stay in place so ids remain positional, but the graphs link
+        only the live rows, so no search spends frontier slots on a
+        deleted point."""
         if self.shards is not None:
             from repro.sharded_search import build_sharded_index
             return build_sharded_index(
                 snap, self.shards, self.metric, M=self.M,
                 builder=self.builder, quantized=self.quantized,
-                scale_rows=self.scale_rows, seed=self.seed)
+                scale_rows=self.scale_rows, seed=self.seed, dead=dead)
+        live = (None if dead is None or not dead.any()
+                else np.flatnonzero(~dead))
+        rows = snap if live is None else snap[live]
         if self.builder == "hnsw":
             from repro.index.hnsw import build_hnsw
-            g = build_hnsw(snap, self.metric, M=self.M, seed=self.seed)
+            g = build_hnsw(rows, self.metric, M=self.M, seed=self.seed)
         else:
             from repro.index.flat import build_knn_graph
-            g = build_knn_graph(snap, self.metric, M=self.M, seed=self.seed)
+            g = build_knn_graph(rows, self.metric, M=self.M, seed=self.seed)
+        if live is not None:
+            g = embed_live_graph(g, live, snap)
         return self._wrap_quantized(snap, g)
 
     def request_rebuild(self, *, background: bool | None = None) -> bool:
         """Kick off a rebuild over the current rows; returns True if one was
-        started (False: one is already running or awaiting its swap).
+        started (False: one is already running, or built over rows that are
+        all still live and awaiting its swap).
 
         ``background=True`` builds on a thread (numpy's BLAS releases the
         GIL, so serving keeps pumping); the built structure is *installed*
         only by ``install_swap`` — the serving layer's between-rounds
-        barrier — never here.
+        barrier — never here. A build is *stale* once ``delete`` hits a row
+        its snapshot holds live: a stale structure is never installed, and
+        the next ``swap_ready`` / ``wait_rebuild`` / ``request_rebuild``
+        replaces it with a build over the current rows.
         """
         with self._lock:
-            if self._pending is not None:
-                return False
             if self._thread is not None and self._thread.is_alive():
                 return False
+            if self._pending is not None and not self._stale:
+                return False
+            self._pending = None
         if self.shards is not None:
             self._pad_for_shards()
         n_snap = self._n
         snap = self._vecs[:n_snap].copy()
+        dead = self._del[:n_snap].copy()
+        self._snap_dead, self._stale = dead, False
 
         def work():
-            art = self._build(snap)
+            try:
+                art = self._build(snap, dead)
+            except Exception as e:  # noqa: BLE001 — handed to the caller
+                with self._lock:
+                    self._error = e
+                return
             with self._lock:
                 self._pending = (n_snap, art)
 
@@ -491,16 +523,38 @@ class MutableIndex:
             self._thread.start()
         else:
             work()
+            self._raise_failed_rebuild()
         return True
 
     def wait_rebuild(self) -> None:
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
+        """Block until the rebuild in flight is built; a stale one is
+        rebuilt over the current rows first."""
+        while True:
+            if self._thread is not None:
+                self._thread.join()
+                self._thread = None
+            self._raise_failed_rebuild()
+            if not (self._stale and self._pending is not None):
+                return
+            self.request_rebuild()
+
+    def _raise_failed_rebuild(self) -> None:
+        with self._lock:
+            err, self._error = self._error, None
+        if err is not None:
+            self._snap_dead = None
+            raise RuntimeError("background index rebuild failed") from err
 
     def swap_ready(self) -> bool:
+        """True when a built structure over still-live rows awaits its
+        swap; a stale one is dropped here and rebuilt."""
+        self._raise_failed_rebuild()
         with self._lock:
-            return self._pending is not None
+            ready, stale = self._pending is not None, self._stale
+        if ready and stale:
+            self.request_rebuild()
+            return self.swap_ready()
+        return ready
 
     def install_swap(self):
         """Adopt the pending structure as the new epoch; returns it.
@@ -511,8 +565,12 @@ class MutableIndex:
         with self._lock:
             if self._pending is None:
                 raise RuntimeError("no rebuilt structure pending")
+            if self._stale:
+                raise RuntimeError("pending rebuild is stale (a delete "
+                                   "hit a row it links); call swap_ready")
             n_snap, art = self._pending
             self._pending = None
+        self._snap_dead = None
         if self.shards is not None:
             self.sharded = art
         else:
@@ -595,10 +653,14 @@ class MutableBackend:
         self.inner.prewarm(**kw)
 
     # -- elastic delegation (only when the inner engine is rescalable) -------
+    @property
+    def rescalable(self) -> bool:
+        return is_rescalable(self.inner)
+
     def __getattr__(self, name):
-        # defined dynamically so a MutableBackend over a non-rescalable
-        # engine does NOT satisfy core.backend.RescalableBackend — the
-        # runtime_checkable isinstance probes these attributes
+        # the rescale surface is served dynamically so a MutableBackend over
+        # a non-rescalable engine has none of it; ``rescalable`` above is
+        # the declared capability the scheduler checks
         if name in ("num_shards", "prepare_rescale", "rescale_options"):
             return getattr(self.inner, name)
         if name == "rescale":

@@ -29,6 +29,7 @@ def _kernel(q_ref, x_ref, o_ref, *, metric: str):
     x = x_ref[...].astype(jnp.float32)          # (BN, d)
     dots = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)      # (BQ, BN)
     if metric == "ip":
         out = dots

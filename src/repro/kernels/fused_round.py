@@ -8,17 +8,19 @@ kernel fuses the whole per-lane round into one ``pallas_call``:
 
 * **grid** — one program per occupied lane (``grid=(B,)``), exactly like
   ``greedy_diversify_batch_pallas``: each program sees its own lane's
-  ``(1, W)`` score row and ``(W, d)`` gathered candidate tile.
-* **similarity scoring** — the candidate tile's pairwise similarities are
-  one MXU Gram block (``dot_general`` + the metric transform, the same math
-  as ``batch_similarity``/``pairwise_adjacency``).
-* **eps-adjacency** — thresholded against the lane's own ``eps`` and stored
-  in **kernel scratch memory** (a ``(W, W)`` int8 VMEM buffer): the
-  adjacency matrix never exists in HBM at all.
-* **greedy diversification** — the k sequential steps of paper §II-B-2 run
-  on-chip against the scratch adjacency: masked argmax, ban the picked
-  row's neighbors, repeat. Picks and their scores stream straight to the
-  outputs.
+  ``(1, 1, W)`` score row and ``(1, W, d)`` gathered candidate tile (3-D
+  blocks with a leading lane axis, so every block's last two dims are
+  whole array dims).
+* **eps-adjacency, one row at a time** — each greedy step needs only the
+  picked candidate's row of G^eps, so the kernel computes that row when it
+  is needed: one ``(1, d) x (d, W)`` MXU pass against the VMEM tile plus
+  the metric transform (the same math as ``pairwise_adjacency``),
+  thresholded against the lane's own ``eps``. Neither the ``(W, W)`` Gram
+  nor the adjacency exists anywhere, so VMEM holds only the tile.
+* **greedy diversification** — the k sequential steps of paper §II-B-2
+  (``greedy_diversify.greedy_select``, shared with the standalone greedy
+  kernel): masked argmax, ban the picked row's neighbors, repeat. Picks and
+  their scores are written once after the loop.
 
 Outputs per lane: ``sel`` (local candidate indices, -1 padded) and
 ``selsc`` (the picked scores — zero where no pick). The wrapper in
@@ -27,13 +29,15 @@ Theorem-2 certificate inputs ``(total, s_K)`` from these plus the masked
 score row (kept outside the kernel so both the ref and Pallas paths share
 one bit-exact reduction).
 
-VMEM budget per program at W=1024, d<=512, f32:
-  scores 4KB + tile 2MB + scratch adj 1MB + outputs 1KB  < 4MB   (OK)
+VMEM per program is the ``(W, d)`` f32 tile, double-buffered: 8 MiB at
+W=2048, d=512. The scoped limit is raised to 100 MiB, so W up to 16384 at
+d=96 and 8192 at d=512 compile for a v5e (``tests/test_tpu_compile.py``
+compiles W=2048, d=512).
 
 Parity contract: identical greedy decisions to ``ref.fused_round`` given
-identical adjacency; the adjacency itself is a thresholded Gram tile whose
-edges can flip vs the jnp oracle only for pairs within one float rounding
-step of ``eps`` (the repo-wide documented near-eps tie caveat) — bit-exact
+identical adjacency; an adjacency row is a thresholded row of the Gram
+tile whose edges can flip vs the jnp oracle only for pairs within one float
+rounding step of ``eps`` (the repo-wide documented near-eps tie caveat) — bit-exact
 on tie-free inputs, which is what ``tests/test_fused_round.py`` pins.
 """
 from __future__ import annotations
@@ -45,54 +49,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.greedy_diversify import greedy_select
 
-def _kernel(eps_ref, scores_ref, vecs_ref, sel_ref, selsc_ref, adj_ref, *,
-            k: int, metric: str):
-    W = scores_ref.shape[1]
-    x = vecs_ref[...].astype(jnp.float32)                 # (W, d) tile
-    eps = eps_ref[0, 0]
+# the (W, d) tile is double-buffered in VMEM; a v5e core has 128 MiB, and
+# the default 16 MiB scoped limit would cap W at 4096 for d = 512
+_VMEM_LIMIT = 100 * 2**20
 
-    # -- similarity scoring: one Gram block on the MXU ------------------------
-    dots = jax.lax.dot_general(
-        x, x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)               # (W, W)
-    if metric == "ip":
-        sims = dots
-    elif metric == "cos":
-        n = jnp.sqrt(jnp.maximum(jnp.sum(x * x, axis=1, keepdims=True), 1e-12))
-        sims = dots / (n * n.T)
-    elif metric == "l2":
-        sq = jnp.sum(x * x, axis=1, keepdims=True)
-        d2 = jnp.maximum(sq + sq.T - 2.0 * dots, 0.0)
-        sims = 1.0 - jnp.sqrt(d2)
-    else:
-        raise ValueError(metric)
 
-    # -- eps-adjacency, thresholded straight into VMEM scratch ----------------
-    # (The diagonal and invalid rows/columns are NOT masked here: the greedy
-    # loop below bans the picked index explicitly, and invalid candidates
-    # carry -inf scores so they are banned from step zero — banning them
-    # again through a spurious edge is a no-op. This keeps the kernel free
-    # of global-index bookkeeping, like pairwise_adjacency_pallas.)
-    adj_ref[...] = (sims > eps).astype(jnp.int8)
+def _kernel(eps_ref, scores_ref, vecs_ref, sel_ref, selsc_ref, *, k: int,
+            metric: str):
+    x = vecs_ref[0]                                       # (W, d) tile
+    eps = eps_ref[0]                                      # (1, 1)
+    sq = jnp.sum(x * x, axis=1, keepdims=True).T          # (1, W)
+    norm = jnp.sqrt(jnp.maximum(sq, 1e-12))
 
-    # -- greedy diversification over the scratch tile -------------------------
-    scores = scores_ref[...]                              # (1, W), -inf = invalid
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    def ban_row(j):
+        # row j of G^eps: one (1, d) x (d, W) MXU pass against the tile.
+        # (Invalid candidates carry -inf scores, so they are banned from
+        # step zero; their edges and the diagonal need no masking here.)
+        xj = vecs_ref[0, pl.ds(j[0, 0], 1), :]            # (1, d)
+        dots = jax.lax.dot_general(
+            xj, x, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)           # (1, W)
+        if metric == "ip":
+            sims = dots
+        elif metric == "cos":
+            nj = jnp.sqrt(jnp.maximum(jnp.sum(xj * xj, axis=1,
+                                              keepdims=True), 1e-12))
+            sims = dots / (nj * norm)
+        elif metric == "l2":
+            sqj = jnp.sum(xj * xj, axis=1, keepdims=True)
+            sims = 1.0 - jnp.sqrt(jnp.maximum(sqj + sq - 2.0 * dots, 0.0))
+        else:
+            raise ValueError(metric)
+        return sims > eps
 
-    def body(t, banned):
-        avail = jnp.where(banned, -jnp.inf, scores)
-        j = jnp.argmax(avail, axis=1)[0]
-        ok = avail[0, j] > -jnp.inf
-        pick = jnp.where(ok, j, -1).astype(jnp.int32)
-        pl.store(sel_ref, (slice(0, 1), pl.dslice(t, 1)), pick[None, None])
-        psc = jnp.where(ok, avail[0, j], 0.0).astype(jnp.float32)
-        pl.store(selsc_ref, (slice(0, 1), pl.dslice(t, 1)), psc[None, None])
-        row = pl.load(adj_ref, (pl.dslice(j, 1), slice(None)))   # (1, W)
-        new_banned = banned | (row > 0) | (lane == j)
-        return jnp.where(ok, new_banned, banned)
-
-    jax.lax.fori_loop(0, k, body, ~jnp.isfinite(scores))
+    picks, psc = greedy_select(scores_ref[0], ban_row, k, sel_ref.shape[2])
+    sel_ref[0] = picks
+    selsc_ref[0] = psc
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "interpret"))
@@ -128,31 +123,29 @@ def fused_round_batch_pallas(vectors: jnp.ndarray, ids: jnp.ndarray,
     Wp = -(-W // 128) * 128
     dp = -(-d // 128) * 128
     kp = -(-k // 128) * 128
-    s_p = jnp.full((B, Wp), -jnp.inf, jnp.float32).at[:, :W].set(
+    s_p = jnp.full((B, 1, Wp), -jnp.inf, jnp.float32).at[:, 0, :W].set(
         s_in.astype(jnp.float32))
     v_p = jnp.zeros((B, Wp, dp), jnp.float32).at[:, :W, :d].set(
         vecs.astype(jnp.float32))
-    # flatten the lane axis into rows so each program's tile stays 2D
-    v_rows = v_p.reshape(B * Wp, dp)
-    eps_col = jnp.asarray(eps, jnp.float32).reshape(B, 1)
+    eps_p = jnp.asarray(eps, jnp.float32).reshape(B, 1, 1)
 
     sel, selsc = pl.pallas_call(
         functools.partial(_kernel, k=k, metric=metric),
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, Wp), lambda b: (b, 0)),
-            pl.BlockSpec((Wp, dp), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, Wp), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, Wp, dp), lambda b: (b, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, kp), lambda b: (b, 0)),
-            pl.BlockSpec((1, kp), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, kp), lambda b: (b, 0, 0)),
+            pl.BlockSpec((1, 1, kp), lambda b: (b, 0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((B, kp), jnp.int32),
-            jax.ShapeDtypeStruct((B, kp), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, kp), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, kp), jnp.float32),
         ),
-        scratch_shapes=[pltpu.VMEM((Wp, Wp), jnp.int8)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(eps_col, s_p, v_rows)
-    return sel[:, :k], selsc[:, :k], ids_m, scores_m
+    )(eps_p, s_p, v_p)
+    return sel[:, 0, :k], selsc[:, 0, :k], ids_m, scores_m

@@ -25,6 +25,7 @@ def _kernel(eps_ref, xi_ref, xj_ref, o_ref, *, metric: str):
     eps = eps_ref[0, 0]
     dots = jax.lax.dot_general(
         xi, xj, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     if metric == "ip":
         sims = dots

@@ -2,8 +2,8 @@
 
 The tournament reducer of the sharded search (DESIGN.md §5) repeatedly merges
 two descending-sorted (ids, scores) lists of length L and keeps the top L.
-Concatenating ``a`` (descending) with ``reverse(b)`` (ascending) forms a
-bitonic sequence, so log2(2L) vectorized compare-exchange stages produce a
+Concatenating ``a`` (descending) with ``reverse(b)`` (ascending, reversed
+by the wrapper: Mosaic has no lowering for ``rev``) forms a bitonic sequence, so log2(2L) vectorized compare-exchange stages produce a
 fully sorted result — no data-dependent control flow, pure VPU work.
 
 Comparator matches the ref's lexsort exactly: (score desc, id asc).
@@ -16,31 +16,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _compare_exchange(scores, ids, dist):
-    """One bitonic stage at the given distance over a (1, 2L) vector."""
+    """One bitonic stage at the given distance over a (1, 2L) vector.
+
+    Element i pairs with i ^ dist; the lower index of each pair keeps the
+    (score desc, id asc)-greater element. Partners are fetched with lane
+    rotations and selected by a mask, so no lane-splitting reshape is
+    needed.
+    """
     n = scores.shape[1]
-    s = scores.reshape(n // (2 * dist), 2, dist)
-    i = ids.reshape(n // (2 * dist), 2, dist)
-    s_hi, s_lo = s[:, 0, :], s[:, 1, :]
-    i_hi, i_lo = i[:, 0, :], i[:, 1, :]
-    # "hi" slot should hold the (score desc, id asc)-greater element.
-    take_lo = (s_lo > s_hi) | ((s_lo == s_hi) & (i_lo < i_hi))
-    new_s_hi = jnp.where(take_lo, s_lo, s_hi)
-    new_s_lo = jnp.where(take_lo, s_hi, s_lo)
-    new_i_hi = jnp.where(take_lo, i_lo, i_hi)
-    new_i_lo = jnp.where(take_lo, i_hi, i_lo)
-    s = jnp.stack([new_s_hi, new_s_lo], axis=1).reshape(1, n)
-    i = jnp.stack([new_i_hi, new_i_lo], axis=1).reshape(1, n)
-    return s, i
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    is_hi = (lane & dist) == 0
+
+    def partner(v):   # v[i + dist] on hi slots, v[i - dist] on lo slots
+        return jnp.where(is_hi, pltpu.roll(v, n - dist, 1),
+                         pltpu.roll(v, dist, 1))
+
+    ps, pi = partner(scores), partner(ids)
+    p_greater = (ps > scores) | ((ps == scores) & (pi < ids))
+    self_greater = (scores > ps) | ((scores == ps) & (ids < pi))
+    take = (is_hi & p_greater) | (~is_hi & self_greater)
+    return jnp.where(take, ps, scores), jnp.where(take, pi, ids)
 
 
 def _kernel(sa_ref, ia_ref, sb_ref, ib_ref, so_ref, io_ref, *, length: int):
-    scores = jnp.concatenate(
-        [sa_ref[...], jnp.flip(sb_ref[...], axis=1)], axis=1)  # (1, 2L) bitonic
-    ids = jnp.concatenate(
-        [ia_ref[...], jnp.flip(ib_ref[...], axis=1)], axis=1)
+    # b arrives reversed (ascending), so a ++ b is bitonic.
+    scores = jnp.concatenate([sa_ref[...], sb_ref[...]], axis=1)  # (1, 2L)
+    ids = jnp.concatenate([ia_ref[...], ib_ref[...]], axis=1)
     dist = length
     while dist >= 1:
         scores, ids = _compare_exchange(scores, ids, dist)
@@ -63,6 +68,7 @@ def topk_merge_pallas(ids_a, scores_a, ids_b, scores_b, interpret: bool = False)
 
     ia, sa = pad(ids_a, scores_a)
     ib, sb = pad(ids_b, scores_b)
+    ib, sb = jnp.flip(ib, axis=1), jnp.flip(sb, axis=1)
     so, io = pl.pallas_call(
         functools.partial(_kernel, length=Lp),
         out_shape=(

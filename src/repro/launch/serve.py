@@ -44,6 +44,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.db import DiverseVectorDB
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.serve.policies import ExpansionCostModel
 from repro.serve.rag import RagPipeline
@@ -118,6 +119,7 @@ def main():
     ap.add_argument("--prewarm", action="store_true",
                     help="pre-compile the scheduler's capacity ladder")
     args = ap.parse_args()
+    use_compile_cache()
 
     rng = np.random.default_rng(0)
     docs = rng.normal(size=(args.corpus, args.dim)).astype(np.float32)

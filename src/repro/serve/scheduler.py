@@ -59,8 +59,7 @@ from typing import Callable
 
 import numpy as np
 
-from repro.core.backend import (LaneBackend, LaneRequest,
-                                RescalableBackend)
+from repro.core.backend import LaneBackend, LaneRequest, is_rescalable
 from repro.core.batch_progressive import ProgressiveEngine
 from repro.core.graph import FlatGraph
 from repro.core.pgs import DiverseResult
@@ -363,7 +362,7 @@ class LaneScheduler:
         self._next_wid = 0
         self.steps = 0
         if elastic:
-            if not isinstance(backend, RescalableBackend):
+            if not is_rescalable(backend):
                 raise ValueError(
                     "elastic= needs a rescalable backend (a ShardedEngine, "
                     "bare or under MutableBackend) with prepared targets — "
@@ -827,7 +826,7 @@ class LaneScheduler:
             signatures=len(self.backend.signature_log),
             unplanned_signatures=len(self.backend.signature_log.unplanned),
             shards=(int(self.backend.num_shards)
-                    if isinstance(self.backend, RescalableBackend)
+                    if is_rescalable(self.backend)
                     else None),
             scale_events=len(self.scale_events),
         )

@@ -88,6 +88,7 @@ class ShardedEngine:
     """
 
     methods = ("sharded",)
+    rescalable = True
 
     def __init__(self, index: ShardedIndex, all_vectors, mesh,
                  num_lanes: int = 8, *, axis: str = "data",

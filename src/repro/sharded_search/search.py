@@ -27,6 +27,7 @@ replicated diversify stage.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -35,7 +36,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import quant
-from repro.compat import shard_map
 from repro.core import beam_search as bs
 from repro.core import div_astar as da
 from repro.core import queue as qmod
@@ -60,7 +60,7 @@ class ShardedIndex:
     """
     vectors: jnp.ndarray | None      # f32[P, Ns, d]; None when quantized
     neighbors: jnp.ndarray           # int32[P, Ns, M0]
-    entries: jnp.ndarray             # int32[P]
+    entries: jnp.ndarray             # int32[P] or int32[P, E] candidates
     bases: jnp.ndarray               # int32[P] global-id base of each shard
     codes: jnp.ndarray | None = None       # int8[P, Ns, d] | uint8[P, Ns, M]
     scales: jnp.ndarray | None = None      # f32[P, nb]   (int8 scheme)
@@ -119,12 +119,24 @@ def _corpus_parts(index: ShardedIndex):
             lambda a: quant.PQCorpus(codes=a[0][0], codebooks=a[1]))
 
 
+def _stack_entries(entries) -> jnp.ndarray:
+    """Stack per-shard entry candidates into one [P, E] array; a shard with
+    fewer candidates repeats its first (the medoid), which changes no
+    search's start."""
+    entries = [np.atleast_1d(np.asarray(e, np.int32)) for e in entries]
+    width = max(e.size for e in entries)
+    return jnp.asarray(np.stack([
+        np.concatenate([e, np.full(width - e.size, e[0], np.int32)])
+        for e in entries]))
+
+
 def build_sharded_index(vectors: np.ndarray, num_shards: int, metric: str,
                         M: int = 16, builder="knng",
                         quantized: str | None = None, scale_rows: int = 8,
                         pq_m: int | None = None, pq_codes: int = 256,
                         pq_iters: int = 10, pq_sample: int = 16384,
-                        seed: int = 0) -> ShardedIndex:
+                        seed: int = 0, dead: np.ndarray | None = None
+                        ) -> ShardedIndex:
     """Partition the database round-robin and build one graph per shard.
 
     ``quantized`` in {None, "int8", "pq"} selects the on-device corpus
@@ -133,8 +145,11 @@ def build_sharded_index(vectors: np.ndarray, num_shards: int, metric: str,
     per ``scale_rows`` rows; pq: uint8 codebook indices, codebooks k-means
     trained here on the full corpus and replicated; ``pq_m=None`` picks
     ``quant.default_pq_m`` for the corpus width). Callers keep the float
-    ``vectors`` host-side for the exact rerank stage.
+    ``vectors`` host-side for the exact rerank stage. ``dead`` (bool[n],
+    tombstones) keeps those rows in place but links only the live rows of
+    each shard's graph (``core.graph.embed_live_graph``).
     """
+    from repro.core.graph import embed_live_graph
     from repro.index.flat import build_knn_graph
     from repro.index.hnsw import build_hnsw
 
@@ -155,13 +170,20 @@ def build_sharded_index(vectors: np.ndarray, num_shards: int, metric: str,
     codes, scales = [], []
     for s in range(num_shards):
         chunk = np.asarray(vectors[s * ns:(s + 1) * ns], np.float32)
+        live = (None if dead is None or not dead[s * ns:(s + 1) * ns].any()
+                else np.flatnonzero(~dead[s * ns:(s + 1) * ns]))
+        if live is not None and live.size <= M + 1:
+            live = None   # too few live rows for a graph: link them all
+        rows = chunk if live is None else chunk[live]
         if builder == "hnsw":
-            g = build_hnsw(chunk, metric=metric, M=M)
+            g = build_hnsw(rows, metric=metric, M=M)
         else:
-            g = build_knn_graph(chunk, metric=metric, M=M)
+            g = build_knn_graph(rows, metric=metric, M=M)
+        if live is not None:
+            g = embed_live_graph(g, live, chunk)
         vecs.append(np.asarray(g.vectors))
         nbrs.append(np.asarray(g.neighbors))
-        entries.append(int(g.entry))
+        entries.append(np.asarray(g.entry))
         bases.append(s * ns)
         if quantized == "int8":
             c = quant.quantize_int8(chunk, scale_rows=scale_rows)
@@ -176,7 +198,7 @@ def build_sharded_index(vectors: np.ndarray, num_shards: int, metric: str,
     return ShardedIndex(
         vectors=None if quantized else jnp.asarray(np.stack(vecs)),
         neighbors=jnp.asarray(np.stack(nbrs)),
-        entries=jnp.asarray(np.array(entries, np.int32)),
+        entries=_stack_entries(entries),
         bases=jnp.asarray(np.array(bases, np.int32)),
         codes=jnp.asarray(np.stack(codes)) if quantized else None,
         scales=jnp.asarray(np.stack(scales)) if quantized == "int8" else None,
@@ -250,7 +272,7 @@ def reshard_index(index: ShardedIndex, num_shards: int,
             g = build_knn_graph(chunk, metric=index.metric, M=M)
         vecs.append(np.asarray(g.vectors))
         nbrs.append(np.asarray(g.neighbors))
-        entries.append(int(g.entry))
+        entries.append(np.asarray(g.entry))
     m0 = max(a.shape[1] for a in nbrs)
     nbrs = [np.pad(a, ((0, 0), (0, m0 - a.shape[1])), constant_values=-1)
             for a in nbrs]
@@ -265,7 +287,7 @@ def reshard_index(index: ShardedIndex, num_shards: int,
     return ShardedIndex(
         vectors=None if index.scheme else jnp.asarray(np.stack(vecs)),
         neighbors=jnp.asarray(np.stack(nbrs)),
-        entries=jnp.asarray(np.array(entries, np.int32)),
+        entries=_stack_entries(entries),
         bases=jnp.asarray(np.arange(num_shards, dtype=np.int32) * ns_new),
         codes=codes,
         scales=scales,
@@ -349,11 +371,11 @@ def sharded_topk(index: ShardedIndex, qs: jnp.ndarray, k: int, L: int,
         return ids, scores, jax.lax.psum(steps, axis)
 
     shard_spec = P(axis)
-    fn = shard_map(
-        shard_fn, mesh,
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=tuple(shard_spec if kd == "shard" else P() for kd in kinds)
         + (shard_spec, shard_spec, shard_spec, P()),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P()), check_vma=False,
     )
     ids, scores, expansions = fn(*arrays, index.neighbors,
                                  index.entries, index.bases, qs)
@@ -591,13 +613,14 @@ def _resume_dispatch_fn(index: ShardedIndex, mesh: Mesh, axis: str, K: int,
                 vis_b[None], steps_b[None])
 
     sspec = P(axis)
-    mapped = shard_map(
-        shard_fn, mesh,
+    mapped = jax.shard_map(
+        shard_fn, mesh=mesh,
         in_specs=tuple(sspec if kd == "shard" else P() for kd in kinds)
         + (sspec, sspec, sspec,
            sspec, sspec, sspec, sspec, sspec,
            P(), P(), P(), P(), P()),
         out_specs=(P(), P(), sspec, sspec, sspec, sspec, sspec),
+        check_vma=False,
     )
     fn = jax.jit(mapped)
     _RESUME_DISPATCH_FNS[key] = fn
@@ -693,6 +716,24 @@ def _diversify_batch_gathered(cand_vecs, metric: str, ids, scores, epss,
     return jax.vmap(diversify)(cand_vecs, ids, scores, epss)
 
 
+@functools.lru_cache(maxsize=None)
+def _replicated_diversify(mesh: Mesh, gathered: bool, metric: str, k: int,
+                          K: int, method: str, max_expansions: int):
+    """The diversify stage as one program run on every device of ``mesh``
+    over replicated inputs. The merged candidates come back replicated
+    across the mesh, and Mosaic kernels cannot be partitioned by the
+    compiler, so the stage runs inside a shard_map, not on mesh-sharded
+    arrays."""
+    stage = _diversify_batch_gathered if gathered else _diversify_batch
+
+    def run(vecs, ids, scores, epss):
+        return stage(vecs, metric, ids, scores, epss, k, K, method,
+                     max_expansions)
+
+    return jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False))
+
+
 def exact_rerank_frontier(all_vectors, qs, ids, metric: str):
     """Host-side exact float rerank of merged frontiers (quantized path).
 
@@ -738,14 +779,13 @@ def sharded_diverse_search(index: ShardedIndex, all_vectors: jnp.ndarray,
     ids, scores, expansions = sharded_topk(index, qs, K, K * L_factor, mesh,
                                            axis, merge, with_expansions=True)
     epss = jnp.broadcast_to(jnp.asarray(eps, jnp.float32), (qs.shape[0],))
+    vecs = all_vectors
     if index.scheme is not None:
         ids, scores, vecs = exact_rerank_frontier(all_vectors, qs, ids,
                                                    index.metric)
-        out = _diversify_batch_gathered(vecs, index.metric, ids, scores,
-                                        epss, k, K, method, max_expansions)
-    else:
-        out = _diversify_batch(all_vectors, index.metric, ids, scores, epss,
-                               k, K, method, max_expansions)
+    out = _replicated_diversify(mesh, index.scheme is not None, index.metric,
+                                k, K, method, max_expansions)(
+        vecs, ids, scores, epss)
     if with_expansions:
         return (*out, expansions)
     return out
@@ -777,16 +817,13 @@ def sharded_diverse_resume(index: ShardedIndex, all_vectors: jnp.ndarray,
         index, state, qs, lane_idx, fresh, K, K * L_factor, mesh, axis,
         merge)
     epss = jnp.broadcast_to(jnp.asarray(eps, jnp.float32), (qs.shape[0],))
+    vecs = all_vectors
     if index.scheme is not None:
         ids, scores, vecs = exact_rerank_frontier(all_vectors, qs, ids,
                                                    index.metric)
-        out_ids, out_sc, cert = _diversify_batch_gathered(
-            vecs, index.metric, ids, scores, epss, k, K, method,
-            max_expansions)
-    else:
-        out_ids, out_sc, cert = _diversify_batch(
-            all_vectors, index.metric, ids, scores, epss, k, K, method,
-            max_expansions)
+    out_ids, out_sc, cert = _replicated_diversify(
+        mesh, index.scheme is not None, index.metric, k, K, method,
+        max_expansions)(vecs, ids, scores, epss)
     return out_ids, out_sc, ids, scores, cert, new_state
 
 

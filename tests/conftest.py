@@ -1,12 +1,6 @@
 import numpy as np
 import pytest
 
-try:                                    # real hypothesis when installed (CI)
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:             # hermetic containers: smoke fallback
-    from repro.testing import hypothesis_fallback
-    hypothesis_fallback.install()
-
 
 @pytest.fixture(scope="session")
 def clustered_data():

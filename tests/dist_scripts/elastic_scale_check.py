@@ -21,7 +21,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax.numpy as jnp
 from repro.compat import make_mesh
-from repro.core.backend import LaneRequest, RescalableBackend
+from repro.core.backend import LaneRequest, is_rescalable
 from repro.core.baselines import div_astar_oracle
 from repro.core.theorems import theorem2_recheck
 from repro.db import DiverseVectorDB
@@ -135,7 +135,7 @@ db = DiverseVectorDB(X, "ip", shards="auto", elastic=policy, num_lanes=2,
                      backend_kw=dict(K0=16, resume="beam"),
                      scheduler_kw=dict(max_pending=32, prewarm_capacity=N,
                                        prewarm_ks=(k,)))
-assert isinstance(db.backend, RescalableBackend)
+assert is_rescalable(db.backend)
 assert db.backend.num_shards == 2 and set(db.backend.rescale_options()) == \
     {2, 4}
 

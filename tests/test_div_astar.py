@@ -76,3 +76,23 @@ def test_budget_reports_incomplete():
     res = div_astar(jnp.asarray(scores, jnp.float32), jnp.asarray(adj), 8,
                     max_expansions=5)
     assert not bool(res.complete)
+
+
+def test_hopeless_tail_costs_no_budget():
+    """A long tail of low-scored candidates behind a dense clique of near
+    duplicates: the first sibling that fails the bound ends its level, so
+    the search completes on a small budget and matches the oracle."""
+    rng = np.random.default_rng(3)
+    n, k = 4000, 8
+    scores = np.concatenate([10.0 + rng.random(40), rng.random(n - 40)])
+    adj = np.zeros((n, n), bool)
+    adj[:40, :40] = rng.random((40, 40)) < 0.7
+    adj = np.triu(adj, 1)
+    adj = adj | adj.T
+    res = div_astar(jnp.asarray(scores, jnp.float32), jnp.asarray(adj), k,
+                    max_expansions=20_000)
+    assert bool(res.complete)
+    _, ref_scores, ref_complete = div_astar_ref(scores, adj, k)
+    assert ref_complete
+    np.testing.assert_allclose(np.asarray(res.best_scores), ref_scores,
+                               rtol=1e-5)

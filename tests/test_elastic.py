@@ -251,10 +251,11 @@ def test_migrate_state_capacity_overflow_raises():
 
 
 def test_rescalable_protocol_detection():
-    """The scheduler's elastic trigger feature-detects RescalableBackend:
-    a single-host ProgressiveEngine (wrapped or not) must NOT satisfy it,
-    and asking for elastic= over one is a loud constructor error."""
-    from repro.core.backend import RescalableBackend
+    """The scheduler's elastic trigger checks the declared
+    RescalableBackend capability: a single-host ProgressiveEngine (wrapped
+    or not) must NOT declare it, and asking for elastic= over one is a loud
+    constructor error."""
+    from repro.core.backend import is_rescalable
     from repro.core.batch_progressive import ProgressiveEngine
     from repro.index.flat import build_knn_graph
     from repro.index.mutable import MutableBackend, MutableIndex
@@ -263,10 +264,10 @@ def test_rescalable_protocol_detection():
     x = _corpus(1, n=64)
     eng = ProgressiveEngine(build_knn_graph(x, metric="l2", M=4), 2,
                             max_k=4)
-    assert not isinstance(eng, RescalableBackend)
+    assert not is_rescalable(eng)
     mi = MutableIndex(x, "l2", M=4)
     wrapped = MutableBackend(ProgressiveEngine(mi.graph, 2, max_k=4), mi)
-    assert not isinstance(wrapped, RescalableBackend)
+    assert not is_rescalable(wrapped)
     with pytest.raises(ValueError, match="elastic"):
         LaneScheduler(backend=wrapped, prewarm=False, elastic=True)
 

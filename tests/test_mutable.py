@@ -213,3 +213,111 @@ def test_certificates_reaudited_against_live_corpus(clustered_data):
         assert np.array_equal(np.asarray(sel), np.asarray(res.ids))
     # the write changed the served set (the near-dup outranks base's top)
     assert int(new_ids[0]) not in np.asarray(base.ids).tolist()
+
+
+@pytest.mark.parametrize("background", [True, False])
+def test_failed_rebuild_is_reraised(clustered_data, monkeypatch, background):
+    """A rebuild that raises is not lost: the error surfaces from
+    ``wait_rebuild`` (or at once, in the foreground), no swap is left
+    pending, and the next rebuild starts clean."""
+    idx = MutableIndex(clustered_data, "l2", M=8, background=background)
+
+    def boom(snap, dead=None):
+        raise MemoryError("build failed")
+
+    monkeypatch.setattr(idx, "_build", boom)
+    if background:
+        assert idx.request_rebuild()
+        with pytest.raises(RuntimeError, match="rebuild failed") as err:
+            idx.wait_rebuild()
+    else:
+        with pytest.raises(RuntimeError, match="rebuild failed") as err:
+            idx.request_rebuild()
+    assert isinstance(err.value.__cause__, MemoryError)
+    assert not idx.swap_ready()
+    monkeypatch.undo()
+    assert idx.request_rebuild()
+    idx.wait_rebuild()
+    assert idx.swap_ready()
+
+
+@pytest.mark.parametrize("builder", ["knng", "hnsw"])
+def test_rebuild_unlinks_deleted_rows(clustered_data, builder):
+    """A rebuild keeps tombstoned rows in place (ids stay positional) but
+    links only live rows: no edge leads to or from a deleted row, at any
+    level, and the entry point is live — so no search spends frontier
+    slots on a deleted point."""
+    idx = MutableIndex(clustered_data, "l2", M=8, builder=builder,
+                       background=False)
+    dead = np.array([3, 5, 7, 100, 599])
+    idx.delete(dead)
+    assert idx.request_rebuild()
+    idx.install_swap()
+    g = idx.graph
+    nbrs, upper = np.asarray(g.neighbors), np.asarray(g.upper)
+    assert (nbrs[dead] < 0).all() and not np.isin(nbrs, dead).any()
+    assert (upper[:, dead] < 0).all() and not np.isin(upper, dead).any()
+    assert not np.isin(np.asarray(g.entry), dead).any()
+    np.testing.assert_array_equal(np.asarray(g.vectors), clustered_data)
+    live = np.setdiff1d(np.arange(len(clustered_data)), dead)
+    assert (nbrs[live] >= 0).any(axis=1).all()
+
+
+def _linked(graph, ids) -> bool:
+    nbrs, upper = np.asarray(graph.neighbors), np.asarray(graph.upper)
+    return bool((nbrs[ids] >= 0).any() or np.isin(nbrs, ids).any()
+                or np.isin(upper, ids).any())
+
+
+@pytest.mark.parametrize("background", [True, False])
+def test_delete_after_auto_rebuild_is_honoured(clustered_data, background):
+    """Upserts that fill the delta start a rebuild at once; a delete that
+    lands after that snapshot makes the build stale. The stale structure
+    is never installed: ``rebuild(wait=True)`` builds again over the
+    current rows, so the swapped-in graph links no deleted row."""
+    x = clustered_data
+    db = DiverseVectorDB(x, "l2", M=8, delta_capacity=16, num_lanes=2,
+                         prewarm=False, background_rebuild=background)
+    rng = np.random.default_rng(5)
+    db.upsert(x[:16] + rng.normal(size=(16, x.shape[1])).astype(np.float32)
+              * np.float32(0.01))          # fills the delta: rebuild starts
+    dead = np.array([2, 40, 333])
+    db.delete(dead)
+    assert db.rebuild(wait=True)
+    g = db.index.graph
+    assert g.size == len(x) + 16 and not _linked(g, dead)
+    assert db.index.delta_count == 0 and not db.index.swap_ready()
+    res = db.search(x[40], k=5, eps=0.5)
+    assert not np.isin(np.asarray(res.ids), dead).any()
+
+
+def test_delete_of_unlinked_rows_keeps_pending_build(clustered_data):
+    """A delete that touches only rows the pending snapshot already holds
+    dead (or does not hold) leaves the build in place."""
+    x = clustered_data
+    idx = MutableIndex(x, "l2", M=8, delta_capacity=4, background=False)
+    idx.delete([7])
+    assert idx.request_rebuild()
+    art = idx._pending[1]
+    idx.upsert(x[:2])                      # delta rows: not in the snapshot
+    idx.delete([7, len(x)])
+    assert idx.swap_ready() and idx._pending[1] is art
+    idx.delete([8])                        # linked by the snapshot: stale
+    assert idx.swap_ready() and idx._pending[1] is not art
+    assert not _linked(idx.install_swap(), [7, 8])
+
+
+def test_sharded_rebuild_unlinks_deleted_rows(clustered_data):
+    """Each shard's rebuilt graph links only its live rows (local ids)."""
+    x = clustered_data
+    idx = MutableIndex(x, "l2", M=8, shards=2, background=False)
+    dead = np.array([3, 299, 300, 599])
+    idx.delete(dead)
+    assert idx.request_rebuild()
+    sh = idx.install_swap()
+    ns = sh.neighbors.shape[1]
+    for s in range(2):
+        local = dead[(dead >= s * ns) & (dead < (s + 1) * ns)] - s * ns
+        nbrs = np.asarray(sh.neighbors[s])
+        assert (nbrs[local] < 0).all() and not np.isin(nbrs, local).any()
+        assert not np.isin(np.asarray(sh.entries[s]), local).any()
