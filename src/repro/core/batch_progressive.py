@@ -91,6 +91,7 @@ from repro.core.pgs import DiverseResult
 from repro.core.progressive import SearchStats
 from repro.core.theorems import theorem1_K, theorem2_min_value
 from repro.kernels import ops as kops
+from repro.obs import span
 
 
 # --------------------------------------------------------------- results ----
@@ -153,11 +154,18 @@ class SignatureLog:
     signatures exist — the compile-budget backstop. After ``freeze()``
     (scheduler prewarm done), first-seen signatures are additionally recorded
     in ``unplanned`` so tests can assert the ladder was fully pre-warmed.
+
+    Each call of a jitted program on the step path is noted once, so the
+    counts are also the dispatch counter: ``total`` is the running number
+    of program calls, and its difference across a span of serving is the
+    number made in it. Eager ops (a prefix group's row slices, growth's
+    gathers and scatters) are not counted.
     """
 
     def __init__(self, limit: int | None = 1024):
         self.limit = limit
         self.counts: dict[tuple, int] = {}
+        self.total = 0
         self.frozen = False
         self.unplanned: list[tuple] = []
 
@@ -172,23 +180,13 @@ class SignatureLog:
             if self.frozen:
                 self.unplanned.append(sig)
         self.counts[sig] += 1
+        self.total += 1
 
     def freeze(self) -> None:
         self.frozen = True
 
     def __len__(self) -> int:
         return len(self.counts)
-
-
-def jit_cache_sizes() -> dict[str, int]:
-    """Tracing-cache sizes of the engine's jitted device functions (test
-    hook: a serving pass that recompiles shows up as a growing entry)."""
-    fns = dict(search=_batched_search_loop, rebuild=_rebuild_lanes,
-               prefix=_mask_prefix, adjacency=_batched_adjacency,
-               div_astar=_batched_div_astar, theorem1=_batched_theorem1,
-               fused_round=kops._ref_fused_round_batch)
-    return {name: int(f._cache_size()) for name, f in fns.items()
-            if hasattr(f, "_cache_size")}
 
 
 # ------------------------------------------------------- device functions ----
@@ -356,6 +354,11 @@ def _rebuild_lanes(graph: FlatGraph, qs, state, new_capacity: int):
 _batched_stable_count = jax.jit(jax.vmap(qmod.stable_count))
 
 
+@jax.jit
+def _set_row(a, i, row):
+    return a.at[i].set(row)
+
+
 @functools.partial(jax.jit, static_argnames=("metric",))
 def _batched_adjacency(vectors, ids, eps, metric: str):
     """Per-lane G^eps adjacency; ``eps`` is a per-lane f32 vector so lanes
@@ -368,8 +371,9 @@ def _batched_adjacency(vectors, ids, eps, metric: str):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "max_expansions"))
-def _batched_div_astar(scores, adj, k: int, max_expansions: int):
-    """Batched div-A* + Theorem-2 minValue per lane.
+def _batched_div_astar(scores, ids, adj, k: int, max_expansions: int):
+    """Batched div-A* + Theorem-2 minValue per lane, over the rows' scores
+    with the empty slots (``ids < 0``) masked out.
 
     Lane-serial on device (``lax.map``) rather than vmapped: div-A* trip
     counts are heavy-tailed (the paper's §IV hard cases run 10-100x the
@@ -380,7 +384,8 @@ def _batched_div_astar(scores, adj, k: int, max_expansions: int):
     def one(s, a):
         r = da.div_astar(s, a, k, max_expansions)
         return r, theorem2_min_value(r.best_scores, k)
-    return jax.lax.map(lambda args: one(*args), (scores, adj))
+    masked = jnp.where(ids >= 0, scores, -jnp.inf)
+    return jax.lax.map(lambda args: one(*args), (masked, adj))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -435,7 +440,11 @@ class BatchProgressiveDriver:
         return lane_state.physical_capacity(self.state)
 
     def _ensure_physical(self, cap: int) -> None:
-        self.state = lane_state.pad_lanes(self.state, cap)
+        C = self.physical_capacity
+        if cap > C:
+            with span("engine.pad", capacity=C, to=cap):
+                self.signatures.note("pad", self.B, C, cap)
+                self.state = lane_state.pad_lanes(self.state, cap)
 
     def recycle(self, lane: int, q, capacity0: int) -> None:
         """Hand lane ``lane`` to a new query: fresh solo-equivalent state at
@@ -443,7 +452,8 @@ class BatchProgressiveDriver:
         self._ensure_physical(capacity0)
         self.signatures.note("recycle", self.B, self.physical_capacity)
         self.state = lane_state.recycle_lane(self.graph, self.state, lane, q)
-        self.qs = self.qs.at[lane].set(jnp.asarray(q, jnp.float32))
+        self.signatures.note("set_query", self.B)
+        self.qs = _set_row(self.qs, lane, jnp.asarray(q, jnp.float32))
         self.caps[lane] = capacity0
         self.stats.reset_lane(lane)
 
@@ -460,28 +470,31 @@ class BatchProgressiveDriver:
         grow = mask & (targets > self.caps)
         if not grow.any():
             return
-        self._ensure_physical(int(targets[grow].max()))
-        C = self.physical_capacity
-        for cap in sorted(set(int(c) for c in targets[grow])):
-            idx = np.flatnonzero(grow & (targets == cap))
-            m = len(idx)
-            padded = pow2_padded_indices(idx)
-            g = len(padded)
-            jidx = jnp.asarray(padded)
-            sub = lane_state.select_lanes(self.state, jidx)
-            sub = lane_state.slice_queue_capacity(sub, cap)
-            self.signatures.note("rebuild", g, cap)
-            rebuilt = _rebuild_lanes(self.graph, self.qs[jidx], sub, cap)
-            q = lane_state.pad_queue(rebuilt.queue, C - cap)
-            ridx = jnp.asarray(idx)
-            bq = self.state.queue
-            self.state = bs.SearchState(
-                qmod.Queue(bq.ids.at[ridx].set(q.ids[:m]),
-                           bq.scores.at[ridx].set(q.scores[:m]),
-                           bq.stable.at[ridx].set(q.stable[:m])),
-                self.state.visited, self.state.steps)
-            self.caps[idx] = cap
-            self.stats.growths[idx] += 1
+        top = int(targets[grow].max())
+        with span("engine.grow", capacity=self.physical_capacity, to=top,
+                  lanes=int(grow.sum())):
+            self._ensure_physical(top)
+            C = self.physical_capacity
+            for cap in sorted(set(int(c) for c in targets[grow])):
+                idx = np.flatnonzero(grow & (targets == cap))
+                m = len(idx)
+                padded = pow2_padded_indices(idx)
+                g = len(padded)
+                jidx = jnp.asarray(padded)
+                sub = lane_state.select_lanes(self.state, jidx)
+                sub = lane_state.slice_queue_capacity(sub, cap)
+                self.signatures.note("rebuild", g, cap)
+                rebuilt = _rebuild_lanes(self.graph, self.qs[jidx], sub, cap)
+                q = lane_state.pad_queue(rebuilt.queue, C - cap)
+                ridx = jnp.asarray(idx)
+                bq = self.state.queue
+                self.state = bs.SearchState(
+                    qmod.Queue(bq.ids.at[ridx].set(q.ids[:m]),
+                               bq.scores.at[ridx].set(q.scores[:m]),
+                               bq.stable.at[ridx].set(q.stable[:m])),
+                    self.state.visited, self.state.steps)
+                self.caps[idx] = cap
+                self.stats.growths[idx] += 1
 
     # -- search bursts ------------------------------------------------------
     def ensure_stable(self, targets: np.ndarray,
@@ -502,15 +515,19 @@ class BatchProgressiveDriver:
             min_values = np.full(self.B, -np.inf, np.float32)
         sl = np.where(active, np.minimum(targets, self.caps), 0)
         ms = 4 * self.caps + 64
-        self.signatures.note("search", self.B, self.physical_capacity)
-        self.state = _batched_search_loop(
-            self.graph.vectors, self.graph.neighbors, self.qs, self.state,
-            jnp.asarray(self.caps, jnp.int32), jnp.asarray(sl, jnp.int32),
-            jnp.asarray(min_values, jnp.float32), jnp.asarray(ms, jnp.int32),
-            self.graph.metric)
-        self.stats.search_calls[active] += 1
-        self.stats.expansions = np.asarray(self.state.steps, np.int64).copy()
-        return np.asarray(_batched_stable_count(self.state.queue), np.int64)
+        C = self.physical_capacity
+        with span("engine.search", capacity=C, lanes=int(active.sum())):
+            self.signatures.note("search", self.B, C)
+            self.state = _batched_search_loop(
+                self.graph.vectors, self.graph.neighbors, self.qs, self.state,
+                jnp.asarray(self.caps, jnp.int32), jnp.asarray(sl, jnp.int32),
+                jnp.asarray(min_values, jnp.float32),
+                jnp.asarray(ms, jnp.int32), self.graph.metric)
+            self.stats.search_calls[active] += 1
+            with span("engine.sync", site="steps"):
+                self.stats.expansions = np.asarray(self.state.steps,
+                                                   np.int64).copy()
+            return self.stable_prefix_len()
 
     def expand_until_below(self, min_values: np.ndarray,
                            active: np.ndarray) -> np.ndarray:
@@ -529,7 +546,10 @@ class BatchProgressiveDriver:
         return stable
 
     def stable_prefix_len(self) -> np.ndarray:
-        return np.asarray(_batched_stable_count(self.state.queue), np.int64)
+        self.signatures.note("stable_count", self.B, self.physical_capacity)
+        counts = _batched_stable_count(self.state.queue)
+        with span("engine.sync", site="stable_count"):
+            return np.asarray(counts, np.int64)
 
     # -- candidate prefixes -------------------------------------------------
     def _buckets(self, Ks: np.ndarray) -> np.ndarray:
@@ -669,6 +689,7 @@ class ProgressiveEngine:
         self.out_ids = np.full((self.B, max_k), -1, np.int32)
         self.out_sc = np.zeros((self.B, max_k), np.float32)
         self._unharvested: list[int] = []
+        self.steps = 0
         #: when True, each certificate-bearing round keeps the lane's sorted
         #: candidate frontier host-side (``last_candidates[lane]`` =
         #: ``(cand_ids, cand_scores, slack_or_None)``) so a result's
@@ -835,25 +856,31 @@ class ProgressiveEngine:
 
         Returns the lane indices that finished during this step.
         """
-        finished: list[int] = []
-        smask = (self.status == LANE_PGS) | (self.status == LANE_PDS)
-        stable = np.zeros(self.B, np.int64)
-        if smask.any():
-            targets = np.where(smask, self.K * self.efs, 0)
-            stable = self.driver.ensure_stable(targets, active=smask)
-        gmask = self.status == LANE_PGS
-        if gmask.any():
-            self._pgs_round(gmask, stable, finished)
-        pmask = self.status == LANE_PDS
-        if pmask.any():
-            self._pds_round(pmask, stable)
-        fmask = self.status == LANE_PDS_FIN
-        if fmask.any():
-            self._pds_final(fmask, finished)
-        vmask = self.status == LANE_PSS
-        if vmask.any():
-            self._pss_round(vmask, finished)
-        return finished
+        self.steps += 1
+        with span("engine.step", step=self.steps, lanes=self.active_count()):
+            finished: list[int] = []
+            smask = (self.status == LANE_PGS) | (self.status == LANE_PDS)
+            stable = np.zeros(self.B, np.int64)
+            if smask.any():
+                targets = np.where(smask, self.K * self.efs, 0)
+                stable = self.driver.ensure_stable(targets, active=smask)
+            gmask = self.status == LANE_PGS
+            if gmask.any():
+                with span("diversify.pgs_round", lanes=int(gmask.sum())):
+                    self._pgs_round(gmask, stable, finished)
+            pmask = self.status == LANE_PDS
+            if pmask.any():
+                with span("verify.pds_round", lanes=int(pmask.sum())):
+                    self._pds_round(pmask, stable)
+            fmask = self.status == LANE_PDS_FIN
+            if fmask.any():
+                with span("verify.pds_final", lanes=int(fmask.sum())):
+                    self._pds_final(fmask, finished)
+            vmask = self.status == LANE_PSS
+            if vmask.any():
+                with span("verify.pss_round", lanes=int(vmask.sum())):
+                    self._pss_round(vmask, finished)
+            return finished
 
     def run_to_completion(self) -> None:
         while self.active_count():
@@ -888,8 +915,9 @@ class ProgressiveEngine:
                 self.graph.vectors, ids, scores, Ks_pad,
                 self._group_eps(idx, g), k_g, self.graph.metric,
                 impl=self.kernel_impl)
-            cnt_np = np.asarray(cnt)
-            sid_np, ssc_np = np.asarray(sel_ids), np.asarray(sel_sc)
+            with span("engine.sync", site="pgs_round"):
+                cnt_np = np.asarray(cnt)
+                sid_np, ssc_np = np.asarray(sel_ids), np.asarray(sel_sc)
             for gi, lane in enumerate(idx):
                 count[lane] = cnt_np[gi]
                 self.out_ids[lane, :k_g] = sid_np[gi]
@@ -922,7 +950,9 @@ class ProgressiveEngine:
                                      self._group_eps(idx, g),
                                      self.graph.metric)
             d.signatures.note("theorem1", g, width, k_g)
-            kn = np.asarray(_batched_theorem1(adj, ids >= 0, k_g))
+            kn = _batched_theorem1(adj, ids >= 0, k_g)
+            with span("engine.sync", site="pds_round"):
+                kn = np.asarray(kn)
             K_new[idx] = kn[:len(idx)]
         K_new = np.minimum(K_new, n)
         ex = pmask & (K_new > self.maxK)
@@ -947,11 +977,12 @@ class ProgressiveEngine:
                                      self._group_eps(idx, g),
                                      self.graph.metric)
             d.signatures.note("div_astar", g, width, k_g)
-            masked = jnp.where(ids >= 0, scores, -jnp.inf)
-            res, _ = _batched_div_astar(masked, adj, k_g, self.max_expansions)
-            sets_np = np.asarray(res.best_sets)
-            complete_np = np.asarray(res.complete)
-            ids_np, sc_np = np.asarray(ids), np.asarray(scores)
+            res, _ = _batched_div_astar(scores, ids, adj, k_g,
+                                        self.max_expansions)
+            with span("engine.sync", site="pds_final"):
+                sets_np = np.asarray(res.best_sets)
+                complete_np = np.asarray(res.complete)
+                ids_np, sc_np = np.asarray(ids), np.asarray(scores)
             for gi, lane in enumerate(idx):
                 s = sets_np[gi, k_g - 1]
                 self.out_ids[lane, :k_g] = np.where(
@@ -994,13 +1025,14 @@ class ProgressiveEngine:
                                      self._group_eps(idx, g),
                                      self.graph.metric)
             d.signatures.note("div_astar", g, width, k_g)
-            masked = jnp.where(ids >= 0, scores, -jnp.inf)
-            res, mv = _batched_div_astar(masked, adj, k_g, self.max_expansions)
-            best_scores_np = np.asarray(res.best_scores)
-            sets_np = np.asarray(res.best_sets)
-            complete_np = np.asarray(res.complete)
-            mv_np = np.asarray(mv, np.float64)
-            ids_np, sc_np = np.asarray(ids), np.asarray(scores)
+            res, mv = _batched_div_astar(scores, ids, adj, k_g,
+                                         self.max_expansions)
+            with span("engine.sync", site="pss_round"):
+                best_scores_np = np.asarray(res.best_scores)
+                sets_np = np.asarray(res.best_sets)
+                complete_np = np.asarray(res.complete)
+                mv_np = np.asarray(mv, np.float64)
+                ids_np, sc_np = np.asarray(ids), np.asarray(scores)
             for gi, lane in enumerate(idx):
                 complete[lane] = complete_np[gi]
                 min_values[lane] = mv_np[gi]
@@ -1043,8 +1075,9 @@ class ProgressiveEngine:
 
         Walks the power-of-two physical capacities from the current one up to
         ``max_capacity`` (default: the driver's max) and compiles the search
-        burst, lane recycle, and every power-of-two growth-bucket rebuild at
-        each rung, using throwaway states (the live lane state is untouched
+        burst and its stable count, lane recycle, the pad to each higher
+        rung, and every power-of-two growth-bucket rebuild at each rung, and
+        the query write, using throwaway states (the live lane state is untouched
         and the physical capacity is NOT grown — growth stays on-demand; this
         only fills XLA's compile cache so mid-serving growth never pays a
         trace). Optionally pre-compiles the diversify/verify stages for the
@@ -1069,7 +1102,9 @@ class ProgressiveEngine:
             warmed.append((kind, *shape))
 
         zeros_b = jnp.zeros(self.B, jnp.int32)
-        for cap in caps_ladder:
+        _set_row(qs0, 0, jnp.zeros(dim, jnp.float32))
+        note("set_query", self.B)
+        for i, cap in enumerate(caps_ladder):
             state = lane_state.init_lanes(self.graph, qs0, cap)
             note("init", self.B, cap)
             # zero step budget: compiles the burst, executes nothing
@@ -1079,6 +1114,11 @@ class ProgressiveEngine:
                 jnp.zeros(self.B, jnp.float32), zeros_b, self.graph.metric
             ).queue.ids.block_until_ready()
             note("search", self.B, cap)
+            _batched_stable_count(state.queue)
+            note("stable_count", self.B, cap)
+            for to in caps_ladder[i + 1:]:
+                lane_state.pad_lanes(state, to)
+                note("pad", self.B, cap, to)
             lane_state.recycle_lane(self.graph, state, 0,
                                     np.zeros(dim, np.float32))
             note("recycle", self.B, cap)
@@ -1111,7 +1151,7 @@ class ProgressiveEngine:
                     note("theorem1", g, width, k)
                     _batched_theorem1(adj, ids >= 0, k)
                     note("div_astar", g, width, k)
-                    _batched_div_astar(sc, adj, k, self.max_expansions)
+                    _batched_div_astar(sc, ids, adj, k, self.max_expansions)
         return warmed
 
 

@@ -73,10 +73,16 @@ def physical_capacity(state: bs.SearchState) -> int:
 
 
 def pad_lanes(state: bs.SearchState, new_capacity: int) -> bs.SearchState:
-    """Grow the shared physical queue width (logical capacities unchanged)."""
+    """Grow the shared physical queue width (logical capacities unchanged):
+    one dispatch where it grows, none where it does not."""
     pad = new_capacity - physical_capacity(state)
     if pad <= 0:
         return state
+    return _pad_lanes(state, pad)
+
+
+@functools.partial(jax.jit, static_argnames=("pad",))
+def _pad_lanes(state: bs.SearchState, pad: int) -> bs.SearchState:
     return bs.SearchState(pad_queue(state.queue, pad), state.visited,
                           state.steps)
 

@@ -1,7 +1,7 @@
-"""Production mesh construction (multi-pod dry-run spec).
+"""Production mesh construction (multi-pod spec).
 
 Defined as functions so importing this module never touches jax device
-state — the 512-placeholder-device XLA flag is set only by dryrun.py.
+state.
 """
 from __future__ import annotations
 
@@ -26,10 +26,3 @@ def batch_axes(mesh) -> tuple[str, ...]:
     names = mesh.axis_names
     return ("pod", "data") if "pod" in names else ("data",)
 
-
-HW = dict(
-    # TPU v5e-class constants used by the roofline (per chip)
-    peak_flops_bf16=197e12,     # FLOP/s
-    hbm_bw=819e9,               # B/s
-    ici_bw=50e9,                # B/s per link
-)

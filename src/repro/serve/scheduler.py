@@ -63,6 +63,7 @@ from repro.core.backend import LaneBackend, LaneRequest, is_rescalable
 from repro.core.batch_progressive import ProgressiveEngine
 from repro.core.graph import FlatGraph
 from repro.core.pgs import DiverseResult
+from repro.obs import id_text, span
 from repro.serve import policies as P
 from repro.serve.cache import CacheEntry, SemanticResultCache
 from repro.serve.policies import ExpansionCostModel, make_policy
@@ -481,7 +482,9 @@ class LaneScheduler:
         revalidates against its live query; None falls through to the
         normal admission path. Hit or miss is folded into the cost model's
         per-bucket hit probability either way."""
-        hit = self.cache.lookup(req.q, req.k, req.eps, req.method)
+        with span("sched.cache_probe", rid=req.rid) as sp:
+            hit = self.cache.lookup(req.q, req.k, req.eps, req.method)
+            sp.set_metadata(hit=hit is not None)
         self.cost_model.observe_cache(req.k, req.eps, req.method,
                                       hit=hit is not None,
                                       compressed=self.backend_compressed)
@@ -558,14 +561,18 @@ class LaneScheduler:
     def _refill(self) -> None:
         if self.admission == "lockstep" and self.inflight:
             return  # whole-batch regime: wait for the wave's straggler
-        for lane in self.backend.free_lanes():
-            req = self.policy.pop_next()
-            if req is None:
-                break
-            self.backend.admit(int(lane), req)
-            req.t_admit = self.clock()
-            req.lane = int(lane)
-            self.inflight[int(lane)] = req
+        admitted = []
+        with span("sched.refill") as sp:
+            for lane in self.backend.free_lanes():
+                req = self.policy.pop_next()
+                if req is None:
+                    break
+                self.backend.admit(int(lane), req)
+                req.t_admit = self.clock()
+                req.lane = int(lane)
+                self.inflight[int(lane)] = req
+                admitted.append(req.rid)
+            sp.set_metadata(rids=id_text(admitted))
 
     def _maybe_rescale(self) -> None:
         """Elastic scale trigger, run at the pump boundary (between backend
@@ -624,40 +631,46 @@ class LaneScheduler:
         is the write boundary (contract 15) and, under ``elastic=``, the
         scale boundary (contract 16: in-flight lanes migrate, nothing
         drains)."""
-        if self.write_queue:
-            self.apply_writes()
-        if self.elastic is not None:
-            self._maybe_rescale()
-        self._refill()
-        done: list[Request] = []
-        if self.backend.active_count():
-            self.steps += 1
-            self.backend.step()
-        for lane, result in self.backend.harvest():
-            req = self.inflight.pop(lane)
-            req.result = result
-            req.t_done = self.clock()
-            if self.cache is not None and result.stats.certified:
-                rec = getattr(self.backend, "last_candidates",
-                              [None] * self.num_lanes)[lane]
-                if rec is not None:
-                    cand_ids, cand_scores, *rest = rec
-                    self.cache.admit_request(
-                        req.q, req.k, req.eps, req.method, result,
-                        cand_ids, cand_scores,
-                        slack=rest[0] if rest else None)
-            self.backend.recycle(lane)
-            self.completed.append(req)
-            self.total_completed += 1
-            self.tenant_completed[req.tenant] += 1
-            self.cost_model.observe(
-                req.k, req.eps, req.method,
-                expansions=result.stats.expansions,
-                rounds=result.stats.search_calls,
-                service=req.service,
-                compressed=self.backend_compressed)
-            self.policy.on_complete(req)
-            done.append(req)
+        log = self.backend.signature_log
+        dispatches = log.total
+        with span("sched.pump", step=self.steps) as sp:
+            if self.write_queue:
+                self.apply_writes()
+            if self.elastic is not None:
+                self._maybe_rescale()
+            self._refill()
+            done: list[Request] = []
+            if self.backend.active_count():
+                self.steps += 1
+                self.backend.step()
+            with span("sched.harvest") as hs:
+                for lane, result in self.backend.harvest():
+                    req = self.inflight.pop(lane)
+                    req.result = result
+                    req.t_done = self.clock()
+                    if self.cache is not None and result.stats.certified:
+                        rec = getattr(self.backend, "last_candidates",
+                                      [None] * self.num_lanes)[lane]
+                        if rec is not None:
+                            cand_ids, cand_scores, *rest = rec
+                            self.cache.admit_request(
+                                req.q, req.k, req.eps, req.method, result,
+                                cand_ids, cand_scores,
+                                slack=rest[0] if rest else None)
+                    self.backend.recycle(lane)
+                    self.completed.append(req)
+                    self.total_completed += 1
+                    self.tenant_completed[req.tenant] += 1
+                    self.cost_model.observe(
+                        req.k, req.eps, req.method,
+                        expansions=result.stats.expansions,
+                        rounds=result.stats.search_calls,
+                        service=req.service,
+                        compressed=self.backend_compressed)
+                    self.policy.on_complete(req)
+                    done.append(req)
+                hs.set_metadata(rids=id_text(r.rid for r in done))
+            sp.set_metadata(dispatches=log.total - dispatches)
         return done
 
     def drain(self) -> list[Request]:

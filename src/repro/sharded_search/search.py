@@ -628,9 +628,8 @@ def _resume_dispatch_fn(index: ShardedIndex, mesh: Mesh, axis: str, K: int,
 
 
 def resume_jit_cache_sizes() -> dict[str, int]:
-    """Compile-cache audit for the resume dispatch ladder (test hook,
-    mirroring ``core.batch_progressive.jit_cache_sizes``): the number of
-    distinct dispatch rungs and the total jit traces behind them. A serving
+    """Compile-cache audit for the resume dispatch ladder (test hook): the
+    number of distinct dispatch rungs and the total jit traces behind them. A serving
     pass that recompiles shows up as either number growing."""
     traces = sum(int(f._cache_size()) for f in _RESUME_DISPATCH_FNS.values()
                  if hasattr(f, "_cache_size"))

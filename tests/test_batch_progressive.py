@@ -187,8 +187,9 @@ def test_signature_budget_cap(small_graph_l2):
     graph, x = small_graph_l2
     qs = _queries(x, 2)
     driver = BatchProgressiveDriver(graph, qs, ef=10, k=5, capacity0=64,
-                                    max_signatures=2)
-    driver.ensure_stable(np.full(2, 40))   # "init" + "search" fill the budget
+                                    max_signatures=3)
+    # "init", "search" and "stable_count" fill the budget
+    driver.ensure_stable(np.full(2, 40))
     with pytest.raises(SignatureBudgetExceeded):
         driver._grow_lanes(np.array([200, 200]), np.ones(2, bool))
 

@@ -3,12 +3,25 @@ backpressure, latency stats, and the pre-warmed compile ladder."""
 import numpy as np
 import pytest
 
-from repro.core.batch_progressive import jit_cache_sizes
+from repro.core import batch_progressive as bp
+from repro.core import lane_state
 from repro.core.pds import pds
 from repro.core.pss import pss
 from repro.index.flat import build_knn_graph
 from repro.serve.scheduler import (LaneScheduler, SchedulerSaturated,
                                    jain_fairness)
+
+
+def jit_cache_sizes() -> dict[str, int]:
+    """Tracing-cache sizes of the engine's jitted device functions: a
+    serving pass that recompiles shows up as a growing entry."""
+    fns = dict(search=bp._batched_search_loop, rebuild=bp._rebuild_lanes,
+               prefix=bp._mask_prefix, adjacency=bp._batched_adjacency,
+               div_astar=bp._batched_div_astar, theorem1=bp._batched_theorem1,
+               stable_count=bp._batched_stable_count, set_row=bp._set_row,
+               recycle=lane_state._recycle, pad=lane_state._pad_lanes,
+               fused_round=bp.kops._ref_fused_round_batch)
+    return {name: int(f._cache_size()) for name, f in fns.items()}
 
 
 @pytest.fixture(scope="module")
