@@ -24,8 +24,8 @@ from pathlib import Path
 
 from bench.trace import (DEVICE_PLANE, OPS_LINE, WINDOW_SPAN, _clip,
                          _union, find_xplane)
+from bench.trace import PROGRAM_PREFIXES as PREFIXES
 
-PREFIXES = ("sched.", "engine.", "diversify.", "verify.")
 MODULES_LINE = "XLA Modules"
 #: the XLA module of the engine's beam search (``core.batch_progressive``)
 BEAM_SEARCH = "jit__batched_search_loop"

@@ -9,7 +9,7 @@ import dataclasses
 class RunRecord:
     loop: str                   # "backlog" (closed) or "open" (Poisson)
     num_lanes: int
-    setup_s: float              # process start to the window's start
+    setup_s: float              # build, and the warm-up's program obtains
     t_start: float              # the window, on the scheduler's clock
     t_end: float
     sent: list                  # bench.loops.Sent of the window

@@ -9,11 +9,16 @@ graph from ``index.flat.build_knn_graph`` -> ``LaneScheduler`` (``try_submit``
 / ``pump``) -> ``ProgressiveEngine`` -> ``kernels/ops`` on the compiled
 Pallas rung.
 
-Set-up (``setup_s``, process start to the window's start): the corpus,
-eps and the query pool from the deployment's data seed, the graph (built,
-or read from ``bench/.cache/graphs``), the DB with its prewarmed capacity
-ladder, and warm-up passes that rehearse the window's own traffic until
-one obtains no program. Then the window: ``--seconds`` of the cell's loop
+Set-up: the corpus, eps and the query pool from the deployment's data
+seed, the graph (built, or read from ``bench/.cache/graphs``), the DB with
+its prewarmed capacity ladder, and warm-up passes that rehearse the
+window's own traffic until one obtains no program. ``setup_s`` is what a
+user of the program pays before serving: the wall seconds from process
+start to the end of the DB's build, plus the seconds in which JAX was
+obtaining programs (tracing, lowering, compiling or loading from the
+persistent cache) during the warm-up. The warm-up's serving time is not
+in it: how many requests a pass replays grows with the program's speed,
+and no user replays them. Then the window: ``--seconds`` of the cell's loop
 over queries that the seed picks from the pool and orders, with nothing
 compiled in it (the count is printed). After it, every request of the window is served to
 its answer (a minute's grace), device memory is read, the program is freed,
@@ -52,6 +57,11 @@ from bench.record import RunRecord  # noqa: E402
 from bench.trace import WINDOW_SPAN  # noqa: E402
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+#: JAX's events around obtaining one program, each by its phase; the
+#: backend compile wraps ``compile_or_get_cached``, so it covers loads too
+OBTAIN_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                 BACKEND_COMPILE: "compile"}
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
 #: Pallas kernels in the device trace: Mosaic custom calls
 KERNEL_NAMES = ("tpu_custom_call",)
@@ -69,19 +79,43 @@ def log(msg: str) -> None:
 
 class CompileCounter:
     """Executables JAX obtained (compiled, or loaded from the persistent
-    cache) and how many of them were loads."""
+    cache), how many of them were loads, and when it was obtaining them:
+    one ``(phase, start, end)`` span, on the wall clock, per event of
+    ``OBTAIN_PHASES``."""
 
     def __init__(self):
         self.n = 0
         self.loaded = 0
+        self.spans = []
 
-    def on_duration(self, event, secs, **_):
+    def on_span(self, event, start, end, **_):
+        phase = OBTAIN_PHASES.get(event)
+        if phase is None:
+            return
+        self.spans.append((phase, start, end))
         if event == BACKEND_COMPILE:
             self.n += 1
 
     def on_event(self, event, **_):
         if event == CACHE_HIT:
             self.loaded += 1
+
+    def obtain_s(self, since: int = 0) -> float:
+        """Seconds in which JAX was obtaining a program, over the spans
+        from number ``since`` on: their union, since a jitted function
+        traced inside another's trace nests in it."""
+        out, end = 0.0, float("-inf")
+        for _, s, e in sorted(self.spans[since:], key=lambda sp: sp[1]):
+            out += max(0.0, e - max(s, end))
+            end = max(end, e)
+        return out
+
+    def phase_s(self, since: int = 0) -> dict:
+        """Summed seconds of each phase, over the spans from ``since``."""
+        out = dict.fromkeys(OBTAIN_PHASES.values(), 0.0)
+        for phase, s, e in self.spans[since:]:
+            out[phase] += e - s
+        return out
 
 
 _COUNTER: CompileCounter | None = None
@@ -93,8 +127,7 @@ def compile_counter() -> CompileCounter:
     if _COUNTER is None:
         import jax.monitoring
         _COUNTER = CompileCounter()
-        jax.monitoring.register_event_duration_secs_listener(
-            _COUNTER.on_duration)
+        jax.monitoring.register_event_time_span_listener(_COUNTER.on_span)
         jax.monitoring.register_event_listener(_COUNTER.on_event)
     return _COUNTER
 
@@ -191,7 +224,7 @@ def rehearse(sched, traffic, stream, k, eps, seconds, seed, counter):
     open loop)."""
     count = rehearsal_count(traffic, seconds)
     for p in range(1, REHEARSALS + 1):
-        c0 = counter.n
+        c0, i0 = counter.n, len(counter.spans)
         sent, t0, t1, waiting = serve(sched, traffic, stream, k, eps,
                                       1.1 * seconds, window_rng(seed),
                                       count=count)
@@ -201,7 +234,8 @@ def rehearse(sched, traffic, stream, k, eps, seconds, seed, counter):
             raise RuntimeError(f"warm-up: {len(left)} requests unanswered")
         rate = len(sent) / max(t1 - t0, 1e-9)
         log(f"warm-up pass {p}: {len(sent)} requests at {rate!r} req/s, "
-            f"{counter.n - c0} programs obtained")
+            f"{counter.n - c0} programs obtained in "
+            f"{counter.obtain_s(i0)!r} s")
         if count is not None and 1.1 * rate * seconds > count:
             count = int(np.ceil(1.1 * rate * seconds))
         elif counter.n == c0:
@@ -374,20 +408,27 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     t = time.monotonic()
     c0 = counter.n
     db = build_db(cell, graph, metric, impl, scheduler_kw)
-    phases["db_prewarm_s"] = time.monotonic() - t
+    t_built = time.monotonic()
+    phases["db_prewarm_s"] = t_built - t
     phases["prewarm_programs"] = counter.n - c0
+    phases["build_s"] = t_built - t_process
     frontiers = record_frontiers(db)
     sched = db.scheduler
     t = time.monotonic()
-    c0 = counter.n
+    c0, i0 = counter.n, len(counter.spans)
     stream = D.QueryStream(pool, seed)
     window_s = min(seconds, TRACE_WINDOW_S) if trace else seconds
     phases["warmup_passes"], rehearsed = rehearse(
         sched, traffic, stream, k, eps, window_s, seed, counter)
     phases["warmup_s"] = time.monotonic() - t
     phases["warmup_programs"] = counter.n - c0
+    phases["warmup_obtain_s"] = counter.obtain_s(i0)
+    for phase, secs in counter.phase_s(i0).items():
+        phases[f"warmup_{phase}_s"] = secs
     phases["programs_loaded"] = counter.loaded
     phases["programs_compiled"] = counter.n - counter.loaded
+    setup_s = phases["build_s"] + phases["warmup_obtain_s"]
+    phases["setup_s"] = setup_s
     log("set-up " + " ".join(f"{a}={b!r}" for a, b in phases.items()))
 
     if break_path is not None:
@@ -398,7 +439,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(str(trace_dir))
     c0 = counter.n
-    setup_s = time.monotonic() - t_process
     window_cm = (jax.profiler.TraceAnnotation(WINDOW_SPAN)
                  if trace else contextlib.nullcontext())
     if spans is not None:

@@ -3,9 +3,11 @@ per-operation device time and idle gaps labelled by the host's spans.
 
 Planes whose name starts with ``/device:TPU:`` (and no other suffix) are the
 chips; their ``XLA Ops`` line holds one event per operation run on the
-device. The benchmark's own host spans (``TraceAnnotation`` names starting
-with ``bench.``) are on the host plane, on the same clock. The window is the
-host span ``bench.window``.
+device. The host spans are on the host plane, on the same clock: the
+benchmark's own (``TraceAnnotation`` names starting with ``bench.``) and the
+program's (``sched.``, ``engine.``, ``diversify.``, ``verify.``; see
+``bench.program_trace``). The window is the host span ``bench.window``.
+Each idle gap is labelled by the span that is innermost over most of it.
 
 A device event's name is the HLO instruction's text. Per-op time counts
 top-level events only (a ``while`` op's event spans its body's ops); a
@@ -21,6 +23,9 @@ import re
 
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
+#: the program's spans (``repro.obs``), named ``<layer>.<what>``
+PROGRAM_PREFIXES = ("sched.", "engine.", "diversify.", "verify.")
+HOST_PREFIXES = (SPAN_PREFIX,) + PROGRAM_PREFIXES
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 
@@ -86,17 +91,25 @@ def _top_level(events):
 
 
 def _label(gap, spans):
-    """The innermost host span that covers most of ``gap``."""
+    """The span that is innermost over most of ``gap``: each instant of
+    the gap is charged to the shortest host span over it (spans nest, so
+    that is the innermost), and the span charged most names the gap; on a
+    tie, the one that covers more of it."""
     s0, e0 = gap
-    best, best_key = "no span", (0.0, 0.0)
-    for name, s, e in spans:
-        cover = min(e, e0) - max(s, s0)
-        if cover <= 0:
-            continue
-        key = (cover, -(e - s))      # more cover, then the shorter span
-        if key > best_key:
-            best, best_key = name, key
-    return best
+    over = [(name, max(s, s0), min(e, e0), e - s) for name, s, e in spans
+            if min(e, e0) > max(s, s0)]
+    cuts = sorted({s0, e0} | {t for _, a, b, _ in over for t in (a, b)})
+    charged, cover = {}, {}
+    for name, a, b, _ in over:
+        cover[name] = cover.get(name, 0) + b - a
+    for a, b in zip(cuts, cuts[1:]):
+        inside = [sp for sp in over if sp[1] <= a and b <= sp[2]]
+        if inside:
+            name = min(inside, key=lambda sp: sp[3])[0]
+            charged[name] = charged.get(name, 0) + b - a
+    if not charged:
+        return "no span"
+    return max(charged, key=lambda n: (charged[n], cover[n]))
 
 
 def reduce_profile(profile, kernel_names=(), top: int = 10) -> TraceSummary:
@@ -116,7 +129,7 @@ def reduce_profile(profile, kernel_names=(), top: int = 10) -> TraceSummary:
             for e in line.events:
                 if e.name == WINDOW_SPAN:
                     window = (e.start_ns, e.start_ns + e.duration_ns)
-                elif e.name.startswith(SPAN_PREFIX):
+                elif e.name.startswith(HOST_PREFIXES):
                     spans.append((e.name, e.start_ns,
                                   e.start_ns + e.duration_ns))
     if window is None:

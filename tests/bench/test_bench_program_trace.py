@@ -11,7 +11,7 @@ from jax.profiler import ProfileData
 from bench import spec
 from bench.program_trace import reduce_program
 from bench.record import RunRecord
-from bench.trace import reduce_profile
+from bench.trace import PROGRAM_PREFIXES, reduce_profile
 
 DATA = Path(__file__).parent / "data"
 READERS = ("sched.host_ms_per_step", "engine.sync_ms_per_step",
@@ -140,15 +140,32 @@ def test_search_time_reads_only_the_search_module():
 
 def test_program_spans_leave_the_benchmark_reduction_unchanged():
     """``busy_s``, ``idle_share``, ``op_s``, ``kernel_s`` and the idle
-    gaps read the same with the program's spans as without them."""
+    gaps' lengths read the same with the program's spans as without them;
+    only the gaps' labels change."""
     bare = _without(PROGRAM, 4, 5, 6, 7)
     with_spans = reduce_profile(ProfileData.from_text_proto(PROGRAM),
                                 kernel_names=("fused_round",))
     without = reduce_profile(ProfileData.from_text_proto(bare),
                              kernel_names=("fused_round",))
-    assert with_spans == without
+    for field in ("window_s", "busy_s", "chips", "op_s", "kernel_s"):
+        assert getattr(with_spans, field) == getattr(without, field), field
+    assert [g[1] for g in with_spans.gaps] == [g[1] for g in without.gaps]
     assert with_spans.busy_s == pytest.approx(40e-6)
     assert with_spans.idle_share == pytest.approx(0.6)
+
+
+def test_gaps_name_the_innermost_program_span():
+    """Each instant of an idle gap is charged to the innermost span over
+    it, and the span charged most names the gap. [70, 100): engine.sync
+    5 us, engine.step 15, sched.pump 8, bench.step 2. [40, 60):
+    engine.sync 2, engine.step 4, sched.pump 6, bench.pump 1,
+    engine.search 7. [0, 10): bench.pump 1, sched.pump 4, engine.step 1,
+    engine.search 4; the tie goes to sched.pump, which covers more of the
+    gap."""
+    tr = reduce_profile(ProfileData.from_text_proto(PROGRAM))
+    assert [g[0] for g in tr.gaps] == ["engine.step", "engine.search",
+                                       "sched.pump"]
+    assert [g[1] for g in tr.gaps] == pytest.approx([30e-6, 20e-6, 10e-6])
 
 
 def test_a_program_without_spans_reads_nothing():
@@ -229,3 +246,9 @@ def test_chip_trace():
     assert 0.95 * trace.busy_s < search_s <= trace.busy_s
     # idle time outside any pull is part of the idle time
     assert 0 < got["device.host_bound_share"] < 100 * trace.idle_share
+    # each of the longest idle gaps lies under a program span: the pulls'
+    # waits and the refills, not the benchmark's own pump
+    labels = [g[0] for g in trace.gaps]
+    assert len(labels) == 10
+    assert all(n.startswith(PROGRAM_PREFIXES) for n in labels), labels
+    assert labels[0] == "engine.sync"
